@@ -18,12 +18,14 @@ from repro.core.client import PSClient
 from repro.core.node import Cluster
 from repro.core.tables import RowSchema, TableSpec
 from repro.data.synthetic_ctr import SyntheticCTRStream
+from repro.launch.cache import enable_compile_cache
 from repro.models import ctr as ctr_model
 from repro.train.optim import AdamW
 from repro.train.train_step import make_ctr_train_step
 
 
 def main():
+    enable_compile_cache()
     cfg = TINY
     tmp = tempfile.mkdtemp(prefix="hps_quickstart_")
 

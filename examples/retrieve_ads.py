@@ -25,6 +25,7 @@ import numpy as np
 from repro.configs.ctr_models import TINY
 from repro.core.node import Cluster
 from repro.data.synthetic_ctr import SyntheticCTRStream
+from repro.launch.cache import enable_compile_cache
 from repro.retrieval import RetrievalEngine
 from repro.serve import SnapshotPublisher
 from repro.train.trainer import CTRTrainer, TrainerConfig
@@ -37,6 +38,7 @@ def pooled_user_queries(engine, table, batch, dim):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", type=int, default=6)
     ap.add_argument("--topk", type=int, default=5)

@@ -38,6 +38,7 @@ from repro.configs import get_smoke_config, replace
 from repro.core.client import PSClient
 from repro.core.node import Cluster, NetworkModel
 from repro.core.tables import RowSchema, TableSpec
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.attention import KVCache
 from repro.serve import SnapshotPublisher
@@ -45,6 +46,7 @@ from repro.serve.serve_step import greedy_sample
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--new-tokens", type=int, default=32)
     ap.add_argument("--batch", type=int, default=4)

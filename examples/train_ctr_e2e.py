@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.configs.ctr_models import CTRConfig
 from repro.core.node import Cluster
 from repro.data.synthetic_ctr import SyntheticCTRStream, to_ctr_batch
+from repro.launch.cache import enable_compile_cache
 from repro.models import ctr as ctr_model
 from repro.train.trainer import CTRTrainer, TrainerConfig
 
@@ -51,6 +52,7 @@ def evaluate_auc(tr: CTRTrainer, cfg: CTRConfig, n_batches: int = 4) -> float:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", type=int, default=200)
     ap.add_argument("--keys", type=int, default=6_000_000)

@@ -24,12 +24,14 @@ from repro.core.client import PSClient
 from repro.core.node import Cluster
 from repro.core.tables import RowSchema, TableSpec
 from repro.data.tokens import TokenStream
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.train.optim import AdamW
 from repro.train.train_step import TrainSettings, make_lm_train_step_hier
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=100)
     args = ap.parse_args()

@@ -51,7 +51,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.keys import member_sorted
 from repro.kernels import ops as kops
@@ -141,12 +140,12 @@ class ShardedWorkingTable:
             rows = jnp.where(owned[:, None], rows, 0.0)
             return jax.lax.psum(rows, self.axis)
 
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(self.table_spec, P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(table, slots)
 
     # -- accumulate: grads for all B slots -> owned rows only --------------
@@ -172,12 +171,12 @@ class ShardedWorkingTable:
                 tbl, (sl // S).astype(jnp.int32), g, assume_sorted=assume_sorted
             )
 
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(self.table_spec, P(), P()),
             out_specs=self.table_spec,
-            check_rep=False,
+            check_vma=False,
         )(table, slots, grads)
 
     # -- all_to_all exchange: requests to owners, rows back (p2p ``get``) --
@@ -202,12 +201,12 @@ class ShardedWorkingTable:
             back = jax.lax.all_to_all(rows, self.axis, split_axis=0, concat_axis=0, tiled=True)
             return back.reshape(S * m, d)[restore_r[0]]
 
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(self.table_spec, P(self.axis, None, None), P(self.axis, None)),
             out_specs=P(self.axis, None),
-            check_rep=False,
+            check_vma=False,
         )(table, req, restore)
 
 

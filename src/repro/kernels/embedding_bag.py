@@ -9,7 +9,10 @@ HBM-sized intermediates. Fused, neither intermediate ever exists:
 
 * ids / slot_of / valid arrive via **scalar prefetch**, so the BlockSpec
   ``index_map`` addresses the HBM table row directly — the Pallas pipeline
-  turns the gather into async HBM->VMEM DMAs overlapped with compute;
+  turns the gather into async HBM->VMEM DMAs overlapped with compute. The
+  table is viewed as ``[N, 1, D]`` so that one row is a whole trailing
+  ``(1, block_d)`` block: Mosaic refuses a ``(1, block_d)`` block of a 2-D
+  array, whose second-minor block dim must be a multiple of 8;
 * each grid step adds one (row, d-tile) into its example's pooled
   ``[n_slots, block_d]`` output tile via a VPU masked add (iota == slot);
 * the output tile stays **VMEM-resident** across an example's ``nnz`` steps
@@ -83,7 +86,9 @@ def embedding_bag_pallas(
             grid=grid,
             in_specs=[
                 # table row for (example i, nonzero n), d-tile j
-                pl.BlockSpec((1, bd), lambda i, j, n, ids, slots, vals: (ids[i * nnz + n], j)),
+                pl.BlockSpec(
+                    (None, 1, bd), lambda i, j, n, ids, slots, vals: (ids[i * nnz + n], 0, j)
+                ),
             ],
             # pooled tile: constant over the innermost nnz axis -> resident
             out_specs=pl.BlockSpec((1, n_slots, bd), lambda i, j, n, ids, slots, vals: (i, 0, j)),
@@ -96,6 +101,6 @@ def embedding_bag_pallas(
         # mask semantics, not weights: != 0 keeps float masks from silently
         # truncating differently than the ref/portable paths
         (valid.reshape(-1) != 0).astype(jnp.int32),
-        table,
+        table.reshape(N, 1, D),
     )
     return out.astype(table.dtype)

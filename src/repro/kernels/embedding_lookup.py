@@ -8,7 +8,9 @@ directly — the Pallas pipeline turns this into async HBM->VMEM DMAs that
 overlap with the copy of the previous block (the TPU analogue of the paper's
 NVLink peer-to-peer ``get``).
 
-Grid: (B, D // block_d). Block (1, block_d) of the table at row ids[i].
+Grid: (B, D // block_d). Block (1, block_d) of the table at row ids[i]; the
+table is viewed as ``[N, 1, D]`` so that the row is a whole trailing
+block, which Mosaic accepts for lane-aligned ``block_d``.
 """
 
 from __future__ import annotations
@@ -46,9 +48,9 @@ def embedding_lookup_pallas(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
-            in_specs=[pl.BlockSpec((1, bd), lambda i, j, ids: (ids[i], j))],
-            out_specs=pl.BlockSpec((1, bd), lambda i, j, ids: (i, j)),
+            in_specs=[pl.BlockSpec((None, 1, bd), lambda i, j, ids: (ids[i], 0, j))],
+            out_specs=pl.BlockSpec((None, 1, bd), lambda i, j, ids: (i, 0, j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, D), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, D), table.dtype),
         interpret=interpret,
-    )(ids.astype(jnp.int32), table)
+    )(ids.astype(jnp.int32), table.reshape(N, 1, D)).reshape(B, D)

@@ -21,6 +21,13 @@ DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_D = 512
 
 
+def tiles(B: int, D: int) -> bool:
+    """Whether a [B, D] operand tiles by the kernel's default blocks and by
+    the TPU's (8, 128) vreg tile."""
+    br, bd = min(DEFAULT_BLOCK_ROWS, B), min(DEFAULT_BLOCK_D, D)
+    return B % 8 == 0 and D % 128 == 0 and B % br == 0 and D % bd == 0
+
+
 def _adagrad_kernel(p_ref, a_ref, g_ref, lr_ref, po_ref, ao_ref, *, eps):
     g = g_ref[...].astype(jnp.float32)
     a = a_ref[...] + g * g

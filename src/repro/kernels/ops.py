@@ -1,8 +1,9 @@
 """Public jit'd wrappers over the Pallas kernels, with portable fallbacks.
 
-Dispatch policy: the TPU kernels are the *target*; on this CPU container they
-run under ``interpret=True`` (tests) while production code paths call the
-portable implementations that lower on any backend with the same math:
+Dispatch policy: the TPU kernels are the *target*. Off the TPU they run
+only where a test asks for ``interpret=True``; production code paths there
+call the portable implementations, which lower on any backend with the
+same math:
 
 * ``embedding_lookup`` / ``scatter_add`` / ``adagrad_update`` — jnp gather /
   sorted-segment add / fused arithmetic (XLA fuses these well on TPU too;
@@ -14,6 +15,10 @@ portable implementations that lower on any backend with the same math:
   ``'blockwise'`` (lax.scan streaming softmax: O(S*block) memory, compiles
   everywhere — what the multi-pod dry-run lowers), ``'naive'`` (materializes
   scores; small shapes / decode).
+
+The row kernels (``embedding_lookup``, ``embedding_bag``, ``scatter_add``)
+take Pallas on the TPU only where Mosaic accepts them: see
+:func:`row_kernel_is_pallas`.
 """
 
 from __future__ import annotations
@@ -26,6 +31,7 @@ import jax.numpy as jnp
 
 import numpy as np
 
+from repro.kernels import fused_adagrad
 from repro.kernels import ref as _ref
 from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.embedding_lookup import embedding_lookup_pallas
@@ -37,10 +43,39 @@ from repro.kernels.fused_adagrad import adagrad_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.scatter_add import scatter_add_pallas
 from repro.kernels.topk_mips import topk_mips_pallas
+from repro.metrics import Counters
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+LANES = 128  # one vreg lane tile: the narrowest row block Mosaic accepts
+# Scalar-prefetched id streams live in SMEM, 1 MiB on v5e; the kernel's own
+# scalars need room too, so the ids may take half of it.
+SMEM_ID_BYTES = 1 << 19
+
+# Process-wide dispatch counts. The row-kernel choice is made in Python, so
+# under jit it counts once per trace, not once per call.
+COUNTERS = Counters("row_kernel_xla")
+
+
+def row_kernel_is_pallas(width: int, prefetch_bytes: int) -> bool:
+    """Whether a row kernel (``embedding_lookup``, ``embedding_bag``,
+    ``scatter_add``) runs as Pallas for ``width``-wide rows and
+    ``prefetch_bytes`` of scalar-prefetched ids.
+
+    The rule: Pallas on the TPU when the rows are a whole number of
+    128-lane tiles and the ids fit in ``SMEM_ID_BYTES``; the XLA
+    formulation otherwise, on every backend. Mosaic refuses row blocks
+    narrower than a lane tile — every CTR table is 8 wide — and refuses a
+    prefetch larger than SMEM. Each XLA pick that this rule forces counts
+    under ``row_kernel_xla`` in :data:`COUNTERS`.
+    """
+    if width % LANES or prefetch_bytes > SMEM_ID_BYTES:
+        COUNTERS.inc("row_kernel_xla")
+        return False
+    return _on_tpu()
 
 
 # §Perf toggles (beyond-paper optimizations; see EXPERIMENTS.md).
@@ -61,7 +96,7 @@ BANDED_WINDOW = True
 
 def embedding_lookup(table, ids, *, use_pallas: bool | None = None, interpret: bool | None = None):
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = row_kernel_is_pallas(table.shape[1], 4 * ids.size)
     if use_pallas:
         return embedding_lookup_pallas(table, ids, interpret=not _on_tpu() if interpret is None else interpret)
     return _ref.embedding_lookup_ref(table, ids)
@@ -81,7 +116,7 @@ def scatter_add(
     pass ``assume_sorted=True`` to skip the redundant argsort+gathers.
     """
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = row_kernel_is_pallas(table.shape[1], 4 * ids.size)
     if use_pallas:
         if not assume_sorted:
             order = jnp.argsort(ids)  # duplicates must be consecutive for the kernel
@@ -93,16 +128,22 @@ def scatter_add(
     return _ref.scatter_add_ref(table, ids, grads)
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def adagrad_update(params, accum, grads, lr, *, eps: float = 1e-8, use_pallas: bool | None = None, interpret: bool | None = None):
     """Fused row-Adagrad on the pulled working set.
 
-    Working sets are sized by the batch's unique keys, so their shapes are
-    rarely (8, 128)-tile aligned. The update is purely elementwise, so the
-    wrapper repacks any shape into a lane-aligned [rows, 128] layout (padding
-    strictly less than one (8, 128) tile — NOT naive pad-to-128 columns,
-    which would be a 16x traffic blowup for the paper's emb_dim=8 rows) and
-    every shape takes the fused Pallas path instead of silently falling back
-    to the reference. Zero-padded grads leave padded elements at zero.
+    Working sets are sized by the batch's unique keys, so their shapes
+    rarely tile by the kernel's blocks. The update is purely elementwise, so
+    the wrapper runs any other [B, D] shape on its transposed view [D, B],
+    padded to whole blocks: lane-dense along the working rows, and within
+    one block of padding per dimension. Every shape takes the fused Pallas
+    path. The view is transposed because a row-major flat repack to
+    [B*D/128, 128] makes XLA materialize the 8-wide CTR tables 16x
+    lane-padded inside the train step's scan (4.7 GB of temporaries and a
+    140 s compile for a v5e at model C's working set).
     """
     if use_pallas is None:
         use_pallas = _on_tpu()
@@ -110,18 +151,23 @@ def adagrad_update(params, accum, grads, lr, *, eps: float = 1e-8, use_pallas: b
         return _ref.adagrad_ref(params, accum, grads, lr, eps)
     interpret = not _on_tpu() if interpret is None else interpret
     B, D = params.shape
-    if B % 8 == 0 and D % 128 == 0:
+    if fused_adagrad.tiles(B, D):
         return adagrad_pallas(params, accum, grads, lr, eps=eps, interpret=interpret)
-    n = B * D
-    rows = -(-n // 128)
-    rows += -rows % 8
-    pad = rows * 128 - n
-    repack = lambda x: jnp.pad(x.reshape(-1), (0, pad)).reshape(rows, 128)
+    block_rows = fused_adagrad.DEFAULT_BLOCK_ROWS
+    rows = _round_up(D, 8)
+    if rows > block_rows:
+        rows = _round_up(rows, block_rows)
+    # keep the kernel's default block size in elements
+    block_elems = block_rows * fused_adagrad.DEFAULT_BLOCK_D
+    block_d = min(max(LANES, block_elems // rows // LANES * LANES), _round_up(B, LANES))
+    cols = _round_up(B, block_d)
+    view = lambda x: jnp.pad(x.T, ((0, rows - D), (0, cols - B)))
     p_new, a_new = adagrad_pallas(
-        repack(params), repack(accum), repack(grads), lr, eps=eps, interpret=interpret
+        view(params), view(accum), view(grads), lr,
+        eps=eps, block_d=block_d, interpret=interpret,
     )
-    unpack = lambda x: x.reshape(-1)[:n].reshape(B, D)
-    return unpack(p_new), unpack(a_new)
+    unview = lambda x: x[:D, :B].T
+    return unview(p_new), unview(a_new)
 
 
 # --------------------------------------------------------------------------
@@ -203,13 +249,15 @@ def embedding_bag(
 ):
     """Fused gather + per-(example, slot) sum-pool -> [B, n_slots, emb].
 
-    THE device lookup+pool primitive for CTR training and serving: on TPU
-    the Pallas kernel (one VMEM pass, nothing materialized), elsewhere the
-    segment-sum fallback — both under a custom VJP whose backward emits
-    working-table cotangents straight through ``scatter_add``.
+    THE device lookup+pool primitive for CTR training and serving: the
+    Pallas kernel (one VMEM pass, nothing materialized) where
+    :func:`row_kernel_is_pallas` allows it, elsewhere the segment-sum
+    formulation — both under a custom VJP whose backward emits
+    working-table cotangents straight through ``scatter_add``. The forward
+    prefetches three id streams (ids, slots, mask).
     """
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = row_kernel_is_pallas(table.shape[1], 3 * 4 * slot_ids.size)
     if interpret is None:
         interpret = not _on_tpu()
     valid = valid.astype(jnp.bool_)  # all three impls see identical mask math

@@ -10,7 +10,10 @@ global atomics, so we make collisions *structurally* race-free instead:
   accumulate in VMEM and write back to HBM once;
 * ``input_output_aliases`` makes the update in-place in HBM.
 
-Grid: (B, D // block_d); out block = table row ids[i], d-tile j.
+Grid: (B, D // block_d); out block = table row ids[i], d-tile j. Table
+and grads are viewed as ``[rows, 1, D]`` so that one row is a whole
+trailing ``(1, block_d)`` block, which Mosaic accepts for lane-aligned
+``block_d``.
 """
 
 from __future__ import annotations
@@ -59,12 +62,12 @@ def scatter_add_pallas(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, bd), lambda i, j, ids: (i, j)),  # grads
-                pl.BlockSpec((1, bd), lambda i, j, ids: (ids[i], j)),  # table in
+                pl.BlockSpec((None, 1, bd), lambda i, j, ids: (i, 0, j)),  # grads
+                pl.BlockSpec((None, 1, bd), lambda i, j, ids: (ids[i], 0, j)),  # table in
             ],
-            out_specs=pl.BlockSpec((1, bd), lambda i, j, ids: (ids[i], j)),
+            out_specs=pl.BlockSpec((None, 1, bd), lambda i, j, ids: (ids[i], 0, j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((N, D), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((N, 1, D), table.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(ids.astype(jnp.int32), grads, table)
+    )(ids.astype(jnp.int32), grads.reshape(B, 1, D), table.reshape(N, 1, D)).reshape(N, D)

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from jax.experimental.shard_map import shard_map
 
 from repro.configs import ArchConfig
 from repro.models.common import (
@@ -146,8 +145,8 @@ def install_constraints(mesh: Mesh, rules: dict) -> None:
         def body(tbl, tok):
             return jnp.take(tbl, tok, axis=0)
 
-        return shard_map(
-            body, mesh=mesh, in_specs=(tspec, ispec), out_specs=ospec, check_rep=False
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(tspec, ispec), out_specs=ospec, check_vma=False
         )(table, ids)
 
     set_embed_gather_fn(gather)

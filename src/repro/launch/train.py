@@ -32,6 +32,7 @@ from repro.core.node import Cluster
 from repro.core.tables import RowSchema, TableSpec
 from repro.data.tokens import TokenStream
 from repro.launch import sharding as shd
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import get_model
 from repro.train import checkpoint as ckpt
@@ -40,6 +41,7 @@ from repro.train.train_step import TrainSettings, make_lm_train_step_hier
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="yi-9b")
     ap.add_argument("--scale", choices=["smoke", "full"], default="smoke")

@@ -46,6 +46,10 @@ KNOWN_COUNTERS = frozenset({
     "retrieval_rows_scored", "retrieval_index_builds",
     "retrieval_index_rows", "retrieval_rolls", "retrieval_reranks",
     "retrieval_rerank_rows",
+    # kernel dispatch (kernels/ops.py row_kernel_is_pallas): a row kernel
+    # took the XLA formulation because its rows are narrower than a lane
+    # tile or its prefetched ids exceed SMEM
+    "row_kernel_xla",
 })
 
 

@@ -19,6 +19,7 @@ Logical axis vocabulary (mapped to mesh axes by launch/sharding.py):
 from __future__ import annotations
 
 import math
+import zlib
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -52,7 +53,8 @@ def init_params(schema: dict, rng: jax.Array) -> Params:
                 return jnp.ones(node.shape, node.dtype)
             key = rng
             for p in path:
-                key = jax.random.fold_in(key, hash(p) & 0x7FFFFFFF)
+                # crc32, not hash(): str hashes change from process to process
+                key = jax.random.fold_in(key, zlib.crc32(str(p).encode()) & 0x7FFFFFFF)
             fan = node.shape[node.fan_axis] if node.shape else 1
             scale = node.scale if node.scale is not None else 1.0 / math.sqrt(max(1, fan))
             return (jax.random.normal(key, node.shape, jnp.float32) * scale).astype(node.dtype)

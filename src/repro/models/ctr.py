@@ -130,12 +130,8 @@ def lr_forward(working_table: jax.Array, slot_ids: jax.Array, valid: jax.Array, 
     """working_table: [n_working, 1] per-feature weights. Returns logits [B].
 
     An embedding bag with one slot of width 1: the pooled [B, 1, 1] sum of
-    active feature weights IS the linear score. Width-1 rows degenerate to
-    scalar DMAs on the Pallas grid, so this always takes the segment-sum
-    path."""
-    pooled = kops.embedding_bag(
-        working_table, slot_ids, jnp.zeros_like(slot_ids), valid, 1, use_pallas=False
-    )
+    active feature weights IS the linear score."""
+    pooled = kops.embedding_bag(working_table, slot_ids, jnp.zeros_like(slot_ids), valid, 1)
     return pooled[:, 0, 0] + bias
 
 

@@ -3,11 +3,8 @@ int8 wire format on the cluster's remote serving reads."""
 
 import numpy as np
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # not installed: deterministic fixed-seed fallback
-    from repro.testing.hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.compression import (
     ErrorFeedbackCompressor,

@@ -12,6 +12,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import ShapeSpec, get_smoke_config
 from repro.launch import dryrun as DR
@@ -30,7 +31,8 @@ FAMILIES = ["yi-9b", "olmoe-1b-7b", "hymba-1.5b", "xlstm-1.3b", "whisper-tiny", 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    # Auto axes: the sharding rules constrain through with_sharding_constraint
+    return jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 @pytest.mark.parametrize("arch", FAMILIES)

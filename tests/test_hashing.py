@@ -2,11 +2,8 @@
 
 import numpy as np
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # not installed: deterministic fixed-seed fallback
-    from repro.testing.hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.hashing import OPOSRP
 

@@ -5,11 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # not installed: deterministic fixed-seed fallback
-    from repro.testing.hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.embedding_bag import embedding_bag_pallas
@@ -208,7 +205,7 @@ def test_fused_adagrad(B, D):
     np.testing.assert_allclose(a1, a2, atol=1e-6)
 
 
-@pytest.mark.parametrize("B,D", [(13, 40), (1, 1), (7, 129), (8, 128)])
+@pytest.mark.parametrize("B,D", [(13, 40), (1, 1), (7, 129), (8, 128), (60_000, 8), (264, 128), (5, 300)])
 def test_adagrad_update_pads_to_pallas_path(B, D, monkeypatch):
     """Non-(8,128)-tiling working sets must take the Pallas kernel (padded),
     not silently fall back to the reference path."""

@@ -3,11 +3,8 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import HealthCheck, given, settings
-    from hypothesis import strategies as st
-except ImportError:  # not installed: deterministic fixed-seed fallback
-    from repro.testing.hypothesis_fallback import HealthCheck, given, settings, st
+from hypothesis import HealthCheck, given, settings
+from hypothesis import strategies as st
 
 from repro.core.keys import deterministic_init
 from repro.core.ssd_ps import SSDParameterServer

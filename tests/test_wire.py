@@ -15,11 +15,8 @@ Contract under test:
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # not installed: deterministic fixed-seed fallback
-    from repro.testing.hypothesis_fallback import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.ctr_models import TINY
 from repro.core.compression import (
