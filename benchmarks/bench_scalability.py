@@ -33,7 +33,6 @@ def run(n_nodes: int, tmp: str) -> float:
         node_times = []
         for req, shard_keys in enumerate(per_node):
             t0 = time.perf_counter()
-            lt0, rt0 = cl.pull_local_time, cl.pull_remote_time
             nic0 = cl.network.virtual_time
             cl.pull(shard_keys.astype(np.uint64), requester=req, pin=False)
             host = time.perf_counter() - t0

@@ -356,10 +356,11 @@ class DeviceWorkingSet:
     def assemble(prev_table: jax.Array | None, fresh_rows: jax.Array, plan: ReusePlan) -> jax.Array:
         """Build the [n_working, d] table: device gather of reused rows +
         scatter of the transferred delta. Pure data movement — bitwise."""
-        return assemble_rows(
-            prev_table, fresh_rows,
-            plan.reuse_src, plan.reuse_dst, plan.fresh_dst, plan.n_working,
-        )
+        with jax.named_scope("ws_assemble"):
+            return assemble_rows(
+                prev_table, fresh_rows,
+                plan.reuse_src, plan.reuse_dst, plan.fresh_dst, plan.n_working,
+            )
 
 
 # --------------------------------------------------------------------------

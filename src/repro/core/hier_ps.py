@@ -56,6 +56,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import tracing
 from repro.core.compression import (
     KeyedRowStore,
     WireConfig,
@@ -261,36 +262,40 @@ class HierarchicalPS:
         # the train stage and overlapped with device compute
         self.apply_ready_pushes()
 
-        flat = np.asarray(batch_keys, dtype=np.uint64).reshape(-1)
-        uniq, inverse = np.unique(flat, return_inverse=True)
-        n = len(uniq)
+        bid = self._batch_counter if batch_id is None else batch_id  # span label
+        with tracing.span("ps.keys", batch=bid):
+            flat = np.asarray(batch_keys, dtype=np.uint64).reshape(-1)
+            uniq, inverse = np.unique(flat, return_inverse=True)
+            n = len(uniq)
 
-        with self._lock:
-            if batch_id is not None and batch_id in self._ext_to_seq:
-                entry = self._inflight.get(self._ext_to_seq[batch_id])
-                if entry is not None:
-                    self.stats.dedup_reuses += 1
-                    return entry.ws
-            seq = self._batch_counter
-            self._batch_counter += 1
-            # conflict detection: latest in-flight holder per key (scan the
-            # few in-flight batches newest-first; both key sets are sorted)
-            holder_seq = np.full(n, -1, dtype=np.int64)
-            holder_pos = np.zeros(n, dtype=np.int64)
-            entries = {s: e for s, e in self._inflight.items()}
-            for s in sorted(entries, reverse=True):
-                open_mask = holder_seq < 0
-                if not open_mask.any():
-                    break
-                m, pos = member_sorted(entries[s].ws.keys, uniq)
-                m &= open_mask
-                holder_seq[m] = s
-                holder_pos[m] = pos[m]
-            last_keys = self._last_prepared_keys
-            # last statement under the lock, immediately before the guarded
-            # region: nothing between add and the except can leak the seq
-            # (a leaked seq would hold the token floor back forever)
-            self._preparing.add(seq)
+            with self._lock:
+                if batch_id is not None and batch_id in self._ext_to_seq:
+                    entry = self._inflight.get(self._ext_to_seq[batch_id])
+                    if entry is not None:
+                        self.stats.dedup_reuses += 1
+                        return entry.ws
+                seq = self._batch_counter
+                self._batch_counter += 1
+                # conflict detection: latest in-flight holder per key (scan
+                # the few in-flight batches newest-first; both key sets are
+                # sorted)
+                holder_seq = np.full(n, -1, dtype=np.int64)
+                holder_pos = np.zeros(n, dtype=np.int64)
+                entries = {s: e for s, e in self._inflight.items()}
+                for s in sorted(entries, reverse=True):
+                    open_mask = holder_seq < 0
+                    if not open_mask.any():
+                        break
+                    m, pos = member_sorted(entries[s].ws.keys, uniq)
+                    m &= open_mask
+                    holder_seq[m] = s
+                    holder_pos[m] = pos[m]
+                last_keys = self._last_prepared_keys
+                # last statement under the lock, immediately before the
+                # guarded region: nothing between add and the except can
+                # leak the seq (a leaked seq would hold the token floor back
+                # forever)
+                self._preparing.add(seq)
 
         pinned_fresh = None  # keys pinned by the pull, until entry owns them
         try:
@@ -302,7 +307,8 @@ class HierarchicalPS:
             # ordering guarantees no OLDER in-flight batch still holds such
             # a key.
             if device_resident_prev and last_keys is not None:
-                device_served, _ = member_sorted(last_keys, uniq)
+                with tracing.span("ps.keys", batch=bid):
+                    device_served, _ = member_sorted(last_keys, uniq)
             else:
                 device_served = np.zeros(n, dtype=bool)
             fresh = (holder_seq < 0) & ~device_served
@@ -326,7 +332,8 @@ class HierarchicalPS:
                 # conflict-free (every serial batch after its predecessor's
                 # push landed): the pulled buffer is freshly allocated per
                 # batch, so the working set views straight into it
-                rows = self.cluster.pull(uniq, requester=requester, pin=True)
+                with tracing.span("ps.pull", batch=bid):
+                    rows = self.cluster.pull(uniq, requester=requester, pin=True)
                 pinned_fresh = uniq[fresh]
             else:
                 rows = np.zeros((n, self.cluster.dim), dtype=np.float32)
@@ -334,9 +341,10 @@ class HierarchicalPS:
                     rows[dedup, : self.width] = dedup_rows
                 if n_fresh:
                     # the overlap win: fresh rows pull while predecessors train
-                    rows[fresh] = self.cluster.pull(
-                        uniq[fresh], requester=requester, pin=True
-                    )
+                    with tracing.span("ps.pull", batch=bid):
+                        rows[fresh] = self.cluster.pull(
+                            uniq[fresh], requester=requester, pin=True
+                        )
                     pinned_fresh = uniq[fresh]
             ws = WorkingSet(
                 keys=uniq,
@@ -404,7 +412,7 @@ class HierarchicalPS:
         if n_fresh + n_dd + n_dev < n:
             holder_seq = np.where(device_served, -1, holder_seq)
             try:
-                self._resolve_conflicts(entry, uniq, holder_seq, holder_pos, entries)
+                self._resolve_conflicts(entry, uniq, holder_seq, holder_pos, entries, bid)
             except BaseException:
                 self._forget(entry, unpin=True)
                 raise
@@ -426,6 +434,7 @@ class HierarchicalPS:
         holder_seq: np.ndarray,
         holder_pos: np.ndarray,
         entries: dict[int, _InFlight],
+        bid: int,
     ) -> None:
         """Per-key version forwarding: for each conflicting predecessor (in
         batch order) wait for its training results, copy its pushed rows for
@@ -445,7 +454,8 @@ class HierarchicalPS:
         while work:
             s, idx, pos = work.pop(0)
             src = entries[s]
-            self.deps.wait(self._trained_token(s))
+            with tracing.span("ps.conflict_wait", batch=bid, holder=s):
+                self.deps.wait(self._trained_token(s))
             if src.new_params is None:
                 # aborted without training (token signalled by abort/drain):
                 # an older in-flight batch may still hold a pending update
@@ -470,9 +480,10 @@ class HierarchicalPS:
                 work.sort(key=lambda w: w[0])
                 unheld = idx[h2 < 0]
                 if unheld.size:
-                    pulled = self.cluster.pull(
-                        uniq[unheld], requester=entry.requester, pin=True
-                    )
+                    with tracing.span("ps.pull", batch=bid):
+                        pulled = self.cluster.pull(
+                            uniq[unheld], requester=entry.requester, pin=True
+                        )
                     ws.params[unheld] = pulled[:, : self.emb_dim]
                     if self.opt_dim:
                         ws.opt_state[unheld] = pulled[:, self.emb_dim : self.width]
@@ -656,20 +667,22 @@ class HierarchicalPS:
 
     def _push_entry(self, entry: _InFlight) -> None:
         ws = entry.ws
-        full = self.width == self.cluster.dim
-        rows = (np.empty if full else np.zeros)(
-            (ws.n_working, self.cluster.dim), dtype=np.float32
-        )
-        rows[:, : self.emb_dim] = entry.new_params
-        rows[:, self.emb_dim : self.width] = (
-            entry.new_opt if entry.new_opt is not None else ws.opt_state
-        )
-        # entry.packet (set at deposit when the lossy wire is on) makes the
-        # cluster meter the encoded bytes; the values pushed are the exact
-        # dequantized rows either way
-        self.cluster.push(
-            ws.keys, rows, requester=entry.requester, unpin=True, packet=entry.packet
-        )
+        batch = entry.seq if entry.ext_id is None else entry.ext_id
+        with tracing.span("ps.push", batch=batch):
+            full = self.width == self.cluster.dim
+            rows = (np.empty if full else np.zeros)(
+                (ws.n_working, self.cluster.dim), dtype=np.float32
+            )
+            rows[:, : self.emb_dim] = entry.new_params
+            rows[:, self.emb_dim : self.width] = (
+                entry.new_opt if entry.new_opt is not None else ws.opt_state
+            )
+            # entry.packet (set at deposit when the lossy wire is on) makes
+            # the cluster meter the encoded bytes; the values pushed are the
+            # exact dequantized rows either way
+            self.cluster.push(
+                ws.keys, rows, requester=entry.requester, unpin=True, packet=entry.packet
+            )
 
     def complete_batch(
         self,

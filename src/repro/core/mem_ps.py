@@ -46,6 +46,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro import tracing
 from repro.core.hash_index import U64Index
 from repro.core.ssd_ps import SSDParameterServer
 
@@ -126,28 +127,29 @@ class MemParameterServer:
         """Free ``need`` arena rows in one batched pass (caller checked
         feasibility): LFU victims by (freq, LFU-entry time), then LRU
         victims by recency. Dirty victims are staged for the SSD."""
-        evictable = (self.tier != _FREE) & (self.pins == 0)
-        lfu_rows = np.nonzero(evictable & (self.tier == _LFU))[0]
-        order = np.lexsort((self.lfu_time[lfu_rows], self.freq[lfu_rows]))
-        n_lfu = min(need, len(lfu_rows))
-        victims = lfu_rows[order[:n_lfu]]
-        self.stats.evict_lfu_to_ssd += n_lfu
-        self._n_lfu -= n_lfu
-        if n_lfu < need:
-            lru_rows = np.nonzero(evictable & (self.tier == _LRU))[0]
-            order = np.argsort(self.last_used[lru_rows], kind="stable")
-            lru_victims = lru_rows[order[: need - n_lfu]]
-            self._n_lru -= len(lru_victims)
-            victims = np.concatenate([victims, lru_victims])
-        d = victims[self.dirty[victims]]
-        if d.size:
-            self._pend_add(self.key_of_row[d], self.arena[d])
-        self.index.delete(self.key_of_row[victims])
-        self.tier[victims] = _FREE
-        self.dirty[victims] = False
-        self._give_free(victims)
-        if len(self._pend_index) >= self.flush_batch:
-            self._flush_pending()
+        with tracing.span("mem.evict"):
+            evictable = (self.tier != _FREE) & (self.pins == 0)
+            lfu_rows = np.nonzero(evictable & (self.tier == _LFU))[0]
+            order = np.lexsort((self.lfu_time[lfu_rows], self.freq[lfu_rows]))
+            n_lfu = min(need, len(lfu_rows))
+            victims = lfu_rows[order[:n_lfu]]
+            self.stats.evict_lfu_to_ssd += n_lfu
+            self._n_lfu -= n_lfu
+            if n_lfu < need:
+                lru_rows = np.nonzero(evictable & (self.tier == _LRU))[0]
+                order = np.argsort(self.last_used[lru_rows], kind="stable")
+                lru_victims = lru_rows[order[: need - n_lfu]]
+                self._n_lru -= len(lru_victims)
+                victims = np.concatenate([victims, lru_victims])
+            d = victims[self.dirty[victims]]
+            if d.size:
+                self._pend_add(self.key_of_row[d], self.arena[d])
+            self.index.delete(self.key_of_row[victims])
+            self.tier[victims] = _FREE
+            self.dirty[victims] = False
+            self._give_free(victims)
+            if len(self._pend_index) >= self.flush_batch:
+                self._flush_pending()
 
     def _shrink_lru(self) -> None:
         """Demote the coldest unpinned LRU rows into LFU until the LRU tier

@@ -237,8 +237,6 @@ class Cluster:
             self._wire_node(node)
         if tables is not None:
             self.register_tables(tables)
-        self.pull_local_time = 0.0
-        self.pull_remote_time = 0.0
         # the SanLock sanitizer (REPRO_SANLOCK=1) asserts total_pins()==0 at
         # test teardown for every cluster; registration is a weakref append
         from repro.analysis import sanlock
@@ -311,7 +309,6 @@ class Cluster:
             lo, hi = int(bounds[node_id]), int(bounds[node_id + 1])
             if lo == hi:
                 continue
-            t0 = time.perf_counter()
             try:
                 vals = self._with_recovery(
                     node_id,
@@ -324,16 +321,12 @@ class Cluster:
                         if l < h and self.nodes[nid].alive:
                             self.nodes[nid].mem.unpin(sorted_keys[l:h])
                 raise
-            elapsed = time.perf_counter() - t0
-            if node_id == requester:
-                self.pull_local_time += elapsed
-            else:
+            if node_id != requester:
                 # request keys out + rows back over the NIC; unpinned reads
                 # are serving-style and may ride the int8 wire (pinned
                 # training pulls stay exact)
                 self.network.transfer((hi - lo) * 8)
                 vals = self.network.reply(sorted_keys[lo:hi], vals, serving=not pin)
-                self.pull_remote_time += elapsed
             sorted_out[lo:hi] = vals
         out = np.empty_like(sorted_out)
         out[order] = sorted_out  # one scatter back into request order

@@ -37,6 +37,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Hashable, Iterable, Iterator
 
+from repro import tracing
+
 
 _SENTINEL = object()
 _STOPPED = object()  # returned by stop-aware get when the pipeline is halting
@@ -274,7 +276,9 @@ class Pipeline:
 
         self._threads = [threading.Thread(target=feeder, daemon=True)]
         for i in range(len(self.stages)):
-            self._threads.append(threading.Thread(target=worker, args=(i,), daemon=True))
+            self._threads.append(threading.Thread(
+                target=worker, args=(i,), daemon=True, name=f"stage.{self.stages[i].name}"
+            ))
         for t in self._threads:
             t.start()
 
@@ -318,23 +322,25 @@ class Pipeline:
     # ------------------------------------------------- one job, one stage
     def _run_job(self, stage: Stage, stats: StageStats, item: Any) -> Any:
         attempts = 0
-        while True:
-            t0 = time.perf_counter()
-            try:
-                if stage.timeout is None or not stage.idempotent:
-                    result = stage.fn(item)
-                else:
-                    result = self._run_speculative(stage, stats, item)
-                stats.jobs += 1
-                stats.busy_time += time.perf_counter() - t0
-                return result
-            except DependencyAborted:
-                raise  # the pipeline is dying; re-running cannot succeed
-            except Exception:
-                attempts += 1
-                stats.retries += 1
-                if attempts > stage.max_retries:
-                    raise
+        # job: this stage's item index, which is the batch order
+        with tracing.span(f"stage.{stage.name}", job=stats.jobs):
+            while True:
+                t0 = time.perf_counter()
+                try:
+                    if stage.timeout is None or not stage.idempotent:
+                        result = stage.fn(item)
+                    else:
+                        result = self._run_speculative(stage, stats, item)
+                    stats.jobs += 1
+                    stats.busy_time += time.perf_counter() - t0
+                    return result
+                except DependencyAborted:
+                    raise  # the pipeline is dying; re-running cannot succeed
+                except Exception:
+                    attempts += 1
+                    stats.retries += 1
+                    if attempts > stage.max_retries:
+                        raise
 
     def _run_speculative(self, stage: Stage, stats: StageStats, item: Any) -> Any:
         """Run fn; if it exceeds the straggler timeout, launch a backup and

@@ -47,12 +47,12 @@ from __future__ import annotations
 import os
 import struct
 import threading
-import time
 import zlib
 from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import tracing
 from repro.core.hash_index import U64Index
 from repro.core.keys import deterministic_init
 from repro.metrics import Counters
@@ -90,12 +90,11 @@ class SSDStats:
     bytes_read: int = 0
     rows_read: int = 0
     rows_requested: int = 0
+    rows_initialized: int = 0  # requested rows no file held: fresh init
     files_written: int = 0
     files_read: int = 0
     compactions: int = 0
-    compaction_time: float = 0.0
-    read_time: float = 0.0
-    write_time: float = 0.0
+    compaction_bytes_read: int = 0  # also in bytes_read
 
     @property
     def read_amplification(self) -> float:
@@ -162,7 +161,6 @@ class SSDParameterServer:
         fid = self._next_file_id
         self._next_file_id += 1
         path = self._file_path(fid)
-        t0 = time.perf_counter()
         kb = np.ascontiguousarray(keys, dtype=np.uint64).tobytes()
         vb = np.ascontiguousarray(values, dtype=np.float32).tobytes()
         crc = zlib.crc32(vb, zlib.crc32(kb)) & 0xFFFFFFFF
@@ -170,7 +168,6 @@ class SSDParameterServer:
             f.write(_HEADER.pack(_MAGIC, len(keys), self.dim, crc))
             f.write(kb)
             f.write(vb)
-        self.stats.write_time += time.perf_counter() - t0
         nbytes = _HEADER.size + keys.nbytes + values.nbytes
         self.stats.bytes_written += nbytes
         self.stats.files_written += 1
@@ -185,7 +182,6 @@ class SSDParameterServer:
         meta = self.files[fid]
         if self.faults is not None:
             self.faults.on_file_read(self, meta)
-        t0 = time.perf_counter()
         try:
             with open(meta.path, "rb") as f:
                 head = f.read(_HEADER.size)
@@ -208,7 +204,6 @@ class SSDParameterServer:
             raise SSDCorruptionError(fid, meta.path, "checksum mismatch")
         keys = np.frombuffer(payload[: 8 * n_rows], dtype=np.uint64)
         values = np.frombuffer(payload[8 * n_rows :], dtype=np.float32)
-        self.stats.read_time += time.perf_counter() - t0
         self.stats.bytes_read += _HEADER.size + keys.nbytes + values.nbytes
         self.stats.files_read += 1
         self.stats.rows_read += n_rows
@@ -222,7 +217,7 @@ class SSDParameterServer:
         assert values.shape == (len(keys), self.dim)
         if len(keys) == 0:
             return
-        with self._lock:
+        with tracing.span("ssd.write", rows=len(keys)), self._lock:
             for start in range(0, len(keys), self.file_capacity):
                 sl = slice(start, start + self.file_capacity)
                 k, v = keys[sl], values[sl]
@@ -269,17 +264,21 @@ class SSDParameterServer:
         locs = self.index.lookup(keys)
         found = np.nonzero(locs >= 0)[0]
         if found.size:
-            floc = locs[found]
-            order = np.argsort(floc, kind="stable")  # groups by file id
-            floc, found = floc[order], found[order]
-            fids = floc // self.file_capacity
-            starts = np.concatenate([[0], np.nonzero(np.diff(fids))[0] + 1, [len(fids)]])
-            for s, e in zip(starts[:-1], starts[1:]):
-                _, vals = self._read_file(int(fids[s]))  # file = I/O unit
-                out[found[s:e]] = vals[floc[s:e] % self.file_capacity]
+            with tracing.span("ssd.read", rows=int(found.size)):
+                floc = locs[found]
+                order = np.argsort(floc, kind="stable")  # groups by file id
+                floc, found = floc[order], found[order]
+                fids = floc // self.file_capacity
+                starts = np.concatenate([[0], np.nonzero(np.diff(fids))[0] + 1, [len(fids)]])
+                for s, e in zip(starts[:-1], starts[1:]):
+                    _, vals = self._read_file(int(fids[s]))  # file = I/O unit
+                    out[found[s:e]] = vals[floc[s:e] % self.file_capacity]
         missing = locs < 0
         if missing.any():
-            out[missing] = self.init_rows(keys[missing])
+            n_missing = int(missing.sum())
+            self.stats.rows_initialized += n_missing
+            with tracing.span("ssd.init", rows=n_missing):
+                out[missing] = self.init_rows(keys[missing])
         return out
 
     def init_rows(self, keys: np.ndarray) -> np.ndarray:
@@ -361,51 +360,55 @@ class SSDParameterServer:
             ]
             if not victims:
                 return 0
-            t0 = time.perf_counter()
-            self._in_compact = True
-            try:
-                live_keys: list[np.ndarray] = []
-                live_vals: list[np.ndarray] = []
-                for meta in victims:
-                    try:
-                        fkeys, fvals = self._read_file(meta.file_id)
-                    except SSDCorruptionError:
-                        # victim turned out corrupt: quarantine it (heals or
-                        # degrades its live rows) instead of aborting the
-                        # whole compaction
-                        self._quarantine_locked(meta.file_id)
-                        continue
-                    current = meta.file_id * self.file_capacity + np.arange(len(fkeys))
-                    mask = self.index.lookup(fkeys) == current
-                    if mask.any():
-                        live_keys.append(fkeys[mask])
-                        live_vals.append(fvals[mask])
-                # write survivors as fresh files and erase victims
-                if live_keys:
-                    all_k = np.concatenate(live_keys)
-                    all_v = np.concatenate(live_vals)
-                    for start in range(0, len(all_k), self.file_capacity):
-                        sl = slice(start, start + self.file_capacity)
-                        k, v = all_k[sl], all_v[sl]
-                        fid = self._write_file(k, v)
-                        self.index.set(k, fid * self.file_capacity + np.arange(len(k)))
-                for meta in victims:
-                    if meta.file_id not in self.files:
-                        continue  # quarantined above: already gone
-                    if self._file_refs.get(meta.path, 0) > 0:
-                        # a published snapshot still points here: park the path
-                        # until every referencing version is released
-                        self._orphaned.add(meta.path)
-                    else:
+            bytes_before = self.stats.bytes_read
+            with tracing.span("ssd.compact", files=len(victims)) as sp:
+                self._in_compact = True
+                try:
+                    live_keys: list[np.ndarray] = []
+                    live_vals: list[np.ndarray] = []
+                    for meta in victims:
                         try:
-                            os.remove(meta.path)
-                        except FileNotFoundError:
-                            pass
-                    del self.files[meta.file_id]
-            finally:
-                self._in_compact = False
+                            fkeys, fvals = self._read_file(meta.file_id)
+                        except SSDCorruptionError:
+                            # victim turned out corrupt: quarantine it (heals
+                            # or degrades its live rows) instead of aborting
+                            # the whole compaction
+                            self._quarantine_locked(meta.file_id)
+                            continue
+                        current = meta.file_id * self.file_capacity + np.arange(len(fkeys))
+                        mask = self.index.lookup(fkeys) == current
+                        if mask.any():
+                            live_keys.append(fkeys[mask])
+                            live_vals.append(fvals[mask])
+                    # write survivors as fresh files and erase victims
+                    if live_keys:
+                        all_k = np.concatenate(live_keys)
+                        all_v = np.concatenate(live_vals)
+                        for start in range(0, len(all_k), self.file_capacity):
+                            sl = slice(start, start + self.file_capacity)
+                            k, v = all_k[sl], all_v[sl]
+                            fid = self._write_file(k, v)
+                            self.index.set(k, fid * self.file_capacity + np.arange(len(k)))
+                    for meta in victims:
+                        if meta.file_id not in self.files:
+                            continue  # quarantined above: already gone
+                        if self._file_refs.get(meta.path, 0) > 0:
+                            # a published snapshot still points here: park
+                            # the path until every referencing version is
+                            # released
+                            self._orphaned.add(meta.path)
+                        else:
+                            try:
+                                os.remove(meta.path)
+                            except FileNotFoundError:
+                                pass
+                        del self.files[meta.file_id]
+                finally:
+                    self._in_compact = False
+                    read = self.stats.bytes_read - bytes_before
+                    self.stats.compaction_bytes_read += read
+                    sp.set(bytes_read=read)
             self.stats.compactions += 1
-            self.stats.compaction_time += time.perf_counter() - t0
             return len(victims)
 
     # -------------------------------------------------------------- info

@@ -30,6 +30,7 @@ from typing import Any
 
 import numpy as np
 
+from repro import tracing
 from repro.data.synthetic_ctr import KEY_SEED, SLOT_SEED, RawRecordBatch
 from repro.ingest.staging import StagedBatch, StagingRing
 from repro.kernels import ops as kops
@@ -105,25 +106,26 @@ class DeviceIngestor:
                 "labels": np.asarray(raw.labels, dtype=np.float32),
             },
         )
-        hi_dev, lo_dev, slot_dev = kops.feature_extract(
-            staged.tensors["raw_lo"],
-            staged.tensors["raw_hi"],
-            staged.tensors["valid"],
-            n_keys=self.n_keys,
-            n_slots=self.n_slots,
-            key_seed=self.key_seed,
-            slot_seed=self.slot_seed,
-            use_pallas=self.use_pallas,
-            interpret=self.interpret,
-        )
-        # the one device->host hop: the PS pull wants host u64 keys, so the
-        # two u32 planes (8 bytes/key, same wire cost as before the key
-        # space widened past 2^32) recombine here. np.asarray blocks until
-        # the extraction is done, so downstream stages never see a
-        # half-written plane.
-        keys = (
-            np.asarray(hi_dev).astype(np.uint64) << np.uint64(32)
-        ) | np.asarray(lo_dev).astype(np.uint64)
+        with tracing.span("ingest.extract", batch=raw.batch_id):
+            hi_dev, lo_dev, slot_dev = kops.feature_extract(
+                staged.tensors["raw_lo"],
+                staged.tensors["raw_hi"],
+                staged.tensors["valid"],
+                n_keys=self.n_keys,
+                n_slots=self.n_slots,
+                key_seed=self.key_seed,
+                slot_seed=self.slot_seed,
+                use_pallas=self.use_pallas,
+                interpret=self.interpret,
+            )
+            # the one device->host hop: the PS pull wants host u64 keys, so
+            # the two u32 planes (8 bytes/key, same wire cost as before the
+            # key space widened past 2^32) recombine here. np.asarray blocks
+            # until the extraction is done, so downstream stages never see a
+            # half-written plane.
+            keys = (
+                np.asarray(hi_dev).astype(np.uint64) << np.uint64(32)
+            ) | np.asarray(lo_dev).astype(np.uint64)
         if self.network is not None:
             self.network.transfer(int(keys.nbytes))
         self.counters.inc("ingest_examples", B)

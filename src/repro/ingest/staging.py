@@ -45,6 +45,7 @@ from typing import Any
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.pipeline import DependencyRegistry
 from repro.metrics import Counters
 
@@ -97,7 +98,8 @@ class StagingRing:
             self._seq += 1
         if seq >= self.depth:
             t0 = time.perf_counter()
-            self.deps.wait((_FREE, seq - self.depth))
+            with tracing.span("ingest.ring_wait"):
+                self.deps.wait((_FREE, seq - self.depth))
             self.counters.inc(
                 "ingest_wait_us", int((time.perf_counter() - t0) * 1e6)
             )

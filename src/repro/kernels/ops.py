@@ -190,6 +190,7 @@ def _embedding_bag_segment(table, slot_ids, slot_of, valid, n_slots):
     return pooled.reshape(B, n_slots, table.shape[1]).astype(table.dtype)
 
 
+@jax.named_scope("emb_bag_fwd")
 def _embedding_bag_impl(table, slot_ids, slot_of, valid, n_slots, use_pallas, interpret):
     if use_pallas:
         return embedding_bag_pallas(
@@ -208,6 +209,7 @@ def _embedding_bag_fwd(table, slot_ids, slot_of, valid, n_slots, use_pallas, int
     return out, (table, slot_ids, slot_of, valid)
 
 
+@jax.named_scope("emb_bag_bwd")
 def _embedding_bag_bwd(n_slots, use_pallas, interpret, res, g):
     """Working-table cotangent without autodiff's dense intermediate chain:
     route each nonzero's pooled gradient back to its row (a [B, nnz, emb]

@@ -61,11 +61,12 @@ def embed_pool(
 def _tower_mlp(tower, h: jax.Array) -> jax.Array:
     """The shared fully-connected tower: pooled features -> logits [B]."""
     n = len([k for k in tower if k.startswith("w")])
-    for i in range(n):
-        h = h @ tower[f"w{i}"] + tower[f"b{i}"]
-        if i < n - 1:
-            h = jax.nn.relu(h)
-    return h[:, 0]
+    with jax.named_scope("tower"):
+        for i in range(n):
+            h = h @ tower[f"w{i}"] + tower[f"b{i}"]
+            if i < n - 1:
+                h = jax.nn.relu(h)
+        return h[:, 0]
 
 
 def _bce_with_logits(logits: jax.Array, labels: jax.Array) -> jax.Array:

@@ -177,11 +177,13 @@ def make_ctr_train_step(ctr_cfg, row_lr: float = 0.05, tower_opt: AdamW = AdamW(
                 ),
                 argnums=(0, 1),
             )(tower, table)
-            tower, opt_state = tower_opt.update(grads[0], opt_state, tower)
+            with jax.named_scope("tower_adam"):
+                tower, opt_state = tower_opt.update(grads[0], opt_state, tower)
             # paper: parameters synchronized across GPUs after EVERY
             # mini-batch — the row update applies to the shared table before
             # the next mini-batch sees it
-            table, accum = kops.adagrad_update(table, accum, grads[1], row_lr)
+            with jax.named_scope("row_adagrad"):
+                table, accum = kops.adagrad_update(table, accum, grads[1], row_lr)
             return (tower, opt_state, table, accum), loss
 
         (tower, opt_state, working_table, row_accum), losses = jax.lax.scan(

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.ctr_models import CTRConfig, table_specs
 from repro.core.client import PSClient
 from repro.core.compression import WireConfig
@@ -238,11 +239,14 @@ class CTRTrainer:
         self._prev_table, self._prev_accum = new_table, new_accum
         if plan is not None:
             self._train_seq = plan.seq
+        # the readback waits for the step to finish on the device
+        with tracing.span("train.readback", batch=batch.batch_id):
+            table_host, accum_host = np.asarray(new_table), np.asarray(new_accum)
+            loss = float(metrics["loss"])
         # deferred commit: the pull/push stage thread pushes the rows
         # through MEM-PS -> SSD-PS and forwards them to any successor batch
         # waiting on these keys — this stage stays pure device compute
-        sess.commit(np.asarray(new_table), np.asarray(new_accum), defer=True)
-        loss = float(metrics["loss"])
+        sess.commit(table_host, accum_host, defer=True)
         self.losses.append(loss)
         self.batches_done += 1
         if self.ckpt and self.batches_done % self.tcfg.checkpoint_every == 0:

@@ -27,7 +27,8 @@ def test_remote_vs_local_accounting(tmp_path):
     cl = make_cluster(tmp_path)
     keys = np.arange(1000, dtype=np.uint64)
     cl.pull(keys, requester=0, pin=False)
-    assert cl.pull_local_time > 0 and cl.pull_remote_time > 0
+    # one request and one reply per remote node; the local shard sends none
+    assert cl.network.messages == 2 * (cl.n_nodes - 1)
     # ~3/4 of keys are remote for requester 0
     owners = cl.owner_of(keys)
     assert 0.5 < (owners != 0).mean() < 0.95
