@@ -81,6 +81,11 @@ LOCK_ORDER: tuple[LockSpec, ...] = (
         why="file I/O IS the protected resource (read/write/compact/heal)",
     ),
     LockSpec(
+        "Cluster", "_heal_lock", 55, True, reentrant=True,
+        why="one SSD heal at a time: node segments quarantine from pool "
+        "workers under their SSD lock; the heal reads a snapshot under it",
+    ),
+    LockSpec(
         "RedoLog", "_lock", 60, False,
         why="memory-only append/snapshot; readers copy out under it",
     ),

@@ -17,14 +17,19 @@ pull, failure, reshard) are real code paths.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import shutil
 import threading
 import time
+import weakref
+from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import wait as wait_all
 from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import tracing
 from repro.core.compression import sparse_decode, sparse_encode
 from repro.core.keys import key_to_node, partition_by_owner
 from repro.core.mem_ps import MemParameterServer
@@ -32,6 +37,12 @@ from repro.core.recovery import RedoLog, apply_entries
 from repro.core.ssd_ps import SSDParameterServer
 from repro.core.tables import TableRegistry
 from repro.metrics import Counters
+
+# A pull or push of at least this many keys, over at least two nodes, runs
+# its per-node segments at once on the cluster's pool; a smaller call runs
+# them in node order on the calling thread, where a thread handoff would
+# cost more than it saves (PERF.md §3 gives the measurement).
+CONCURRENT_MIN_KEYS = 32_768
 
 
 @dataclass
@@ -227,6 +238,8 @@ class Cluster:
         # initializer + full redo even before any snapshot is published;
         # restore()/reshard clears this (pre-existing rows aren't derivable)
         self._heal_from_init_ok = True
+        # node segments heal from worker threads: one heal at a time
+        self._heal_lock = threading.RLock()
         self._write_gate = threading.Event()
         self._write_gate.set()
         self.nodes = [
@@ -235,6 +248,10 @@ class Cluster:
         ]
         for node in self.nodes:
             self._wire_node(node)
+        # one worker per node; threads start on first use and end with the
+        # cluster
+        self._pool = ThreadPoolExecutor(n_nodes, thread_name_prefix="ps.node")
+        weakref.finalize(self, self._pool.shutdown, wait=False)
         if tables is not None:
             self.register_tables(tables)
         # the SanLock sanitizer (REPRO_SANLOCK=1) asserts total_pins()==0 at
@@ -293,32 +310,76 @@ class Cluster:
                 attempt += 1
                 self.recover_node(node_id)
 
+    def _segments(self, bounds: np.ndarray) -> list[tuple[int, int, int]]:
+        """(node_id, lo, hi) of every non-empty owner segment, in node order."""
+        return [
+            (n, int(bounds[n]), int(bounds[n + 1]))
+            for n in range(self.n_nodes)
+            if bounds[n] < bounds[n + 1]
+        ]
+
+    def _start_segments(self, name: str, segs, op) -> list | None:
+        """Start ``op(node_id, lo, hi)`` for every segment at once on the
+        pool, each in a ``hps:<name>`` span, and wait for all of them; the
+        futures come back in node order. None — run them serially — for a
+        small call, a call on one node, or while a fault injector is armed:
+        the injector fires by the order of the ops it sees, which must not
+        depend on thread timing."""
+        if len(segs) < 2 or segs[-1][2] - segs[0][1] < CONCURRENT_MIN_KEYS or any(
+            n.faults is not None or n.ssd.faults is not None for n in self.nodes
+        ):
+            return None
+
+        def job(n, lo, hi):
+            with tracing.span(name, node=n, rows=hi - lo):
+                return op(n, lo, hi)
+
+        futs = [self._pool.submit(job, *seg) for seg in segs]
+        wait_all(futs)
+        return futs
+
+    def _segment_result(self, futs, i: int, segs, op):
+        """Segment ``i``'s result, serial or concurrent. A concurrent
+        segment whose owner was down is retried here, on the calling thread
+        and in node order, with ``_with_recovery``'s backoff and recovery."""
+        node_id, lo, hi = segs[i]
+        retry = functools.partial(op, node_id, lo, hi)
+        if futs is None:
+            return self._with_recovery(node_id, retry)
+        try:
+            return futs[i].result()
+        except NodeDownError:
+            if not self.auto_recover:
+                raise
+        return self._with_recovery(node_id, retry)
+
     def pull(self, keys: np.ndarray, requester: int = 0, pin: bool = True) -> np.ndarray:
         """Partitioned pull: local shard from local MEM-PS/SSD-PS, remote
-        shards from peer MEM-PS over the (simulated) network.
+        shards from peer MEM-PS over the (simulated) network. A large call
+        pulls the nodes' segments at once; the NIC metering and the scatter
+        stay on the calling thread, in node order.
 
         Pin-transactional: if a node fails partway (NodeDownError, MEM-PS
-        pin pressure), pins taken by the already-served segments — including
+        pin pressure), pins taken by the segments that ran — including
         rows a failing MEM-PS allocated before raising — are rolled back, so
         a retried or abandoned pull never strands pinned rows."""
         keys = np.asarray(keys, dtype=np.uint64)
         order, bounds = self._partition(keys)
         sorted_keys = keys[order]
         sorted_out = np.empty((len(keys), self.dim), dtype=np.float32)
-        for node_id in range(self.n_nodes):
-            lo, hi = int(bounds[node_id]), int(bounds[node_id + 1])
-            if lo == hi:
-                continue
+        segs = self._segments(bounds)
+
+        def op(n, lo, hi):
+            return self.nodes[n].pull(sorted_keys[lo:hi], pin=pin)
+
+        futs = self._start_segments("node.pull", segs, op)
+        for i, (node_id, lo, hi) in enumerate(segs):
             try:
-                vals = self._with_recovery(
-                    node_id,
-                    lambda n=node_id: self.nodes[n].pull(sorted_keys[lo:hi], pin=pin),
-                )
+                vals = self._segment_result(futs, i, segs, op)
             except BaseException:
-                if pin:  # roll back this + every prior segment's pins
-                    for nid in range(node_id + 1):
-                        l, h = int(bounds[nid]), int(bounds[nid + 1])
-                        if l < h and self.nodes[nid].alive:
+                if pin:  # roll back every segment that ran, this one too
+                    for nid, l, h in segs if futs is not None else segs[: i + 1]:
+                        if self.nodes[nid].alive:
                             self.nodes[nid].mem.unpin(sorted_keys[l:h])
                 raise
             if node_id != requester:
@@ -346,7 +407,8 @@ class Cluster:
         see precisely the rows the receiver reconstructs). ``packet`` — a
         :class:`repro.core.compression.PushPacket` covering these rows — is
         metering-only: remote segments then charge the NIC the encoded
-        segment bytes instead of raw key+f32."""
+        segment bytes instead of raw key+f32. A large call pushes the
+        nodes' segments at once, like ``pull``."""
         if not self._write_gate.wait(timeout=120.0):
             raise RuntimeError("cluster write gate held >120s (pause_writes leak?)")
         keys = np.asarray(keys, dtype=np.uint64)
@@ -359,10 +421,13 @@ class Cluster:
         order, bounds = self._partition(keys)
         sorted_keys = keys[order]
         sorted_vals = values[order]
-        for node_id in range(self.n_nodes):
-            lo, hi = int(bounds[node_id]), int(bounds[node_id + 1])
-            if lo == hi:
-                continue
+        segs = self._segments(bounds)
+
+        def op(n, lo, hi):
+            self.nodes[n].push(sorted_keys[lo:hi], sorted_vals[lo:hi], unpin=unpin)
+
+        futs = self._start_segments("node.push", segs, op)
+        for i, (node_id, lo, hi) in enumerate(segs):
             if node_id != requester:
                 raw = (hi - lo) * (8 + 4 * self.dim)
                 if packet is not None:
@@ -372,12 +437,7 @@ class Cluster:
                     self.network.push_bytes_saved += max(0, raw - enc)
                 else:
                     self.network.transfer(raw)
-            self._with_recovery(
-                node_id,
-                lambda n=node_id, l=lo, h=hi: self.nodes[n].push(
-                    sorted_keys[l:h], sorted_vals[l:h], unpin=unpin
-                ),
-            )
+            self._segment_result(futs, i, segs, op)
         if (
             self.redo is not None
             and self.redo_rows
@@ -534,13 +594,14 @@ class Cluster:
         over here; the previous heal source's pin is released."""
         if self.redo is None or redo_pin is None:
             return
-        idx = self.redo.pin_index(redo_pin)
-        old_pin = self._heal_pin
-        self._heal_src = (directory, int(version), int(idx))
-        self._heal_pin = redo_pin
-        self._heal_view = None
-        if old_pin is not None:
-            self.redo.release(old_pin)
+        with self._heal_lock:
+            idx = self.redo.pin_index(redo_pin)
+            old_pin = self._heal_pin
+            self._heal_src = (directory, int(version), int(idx))
+            self._heal_pin = redo_pin
+            self._heal_view = None
+            if old_pin is not None:
+                self.redo.release(old_pin)
 
     def _heal_rows(self, node: PSNode, keys: np.ndarray):
         """Exact current values for rows lost to an SSD quarantine, or
@@ -555,30 +616,31 @@ class Cluster:
         if self.redo is None:
             return None
         keys = np.asarray(keys, dtype=np.uint64)
-        if self._heal_src is not None:
-            directory, version, idx = self._heal_src
-            if not self.redo.covers(idx):
-                return None  # pin bookkeeping failed us; degrade, don't lie
-            view = self._heal_view
-            if view is None or view.version != version:
-                from repro.serve.snapshot import ServingVersion  # circular import
+        with self._heal_lock:  # two nodes' segments may heal at once
+            if self._heal_src is not None:
+                directory, version, idx = self._heal_src
+                if not self.redo.covers(idx):
+                    return None  # pin bookkeeping failed us; degrade, don't lie
+                view = self._heal_view
+                if view is None or view.version != version:
+                    from repro.serve.snapshot import ServingVersion  # circular import
 
-                view = ServingVersion(directory, version)
-                self._heal_view = view
-            rows = np.empty((len(keys), self.dim), dtype=np.float32)
-            owners = key_to_node(keys, view.n_nodes)
-            for nid in range(view.n_nodes):
-                m = owners == nid
-                if m.any():
-                    rows[m] = view.read(nid, keys[m])
-            entries = self.redo.since(idx)
-        elif self._heal_from_init_ok and self.redo.covers(0):
-            rows = node.ssd.init_rows(keys)
-            entries = self.redo.since(0)
-        else:
-            return None
-        apply_entries(entries, keys, rows)
-        return rows
+                    view = ServingVersion(directory, version)
+                    self._heal_view = view
+                rows = np.empty((len(keys), self.dim), dtype=np.float32)
+                owners = key_to_node(keys, view.n_nodes)
+                for nid in range(view.n_nodes):
+                    m = owners == nid
+                    if m.any():
+                        rows[m] = view.read(nid, keys[m])
+                entries = self.redo.since(idx)
+            elif self._heal_from_init_ok and self.redo.covers(0):
+                rows = node.ssd.init_rows(keys)
+                entries = self.redo.since(0)
+            else:
+                return None
+            apply_entries(entries, keys, rows)
+            return rows
 
     def manifest(self) -> dict:
         self.flush_all()
